@@ -11,6 +11,9 @@ train table1 ...
 """
 from __future__ import annotations
 
+# This parent process must never import jax, and it runs its children one
+# at a time: a chip belongs to one process, so a parent holding it (or two
+# children at once) would leave the running child without the device.
 import json
 import os
 import re

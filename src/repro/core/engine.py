@@ -234,11 +234,8 @@ def as_engine(obj, **kwargs) -> RewardEngine:
     from .executor import WCExecutor
     if isinstance(obj, WCExecutor):
         return ExecutorRewardEngine(obj, **kwargs)
-    try:
-        from .sim_jax import JaxWCEngine
-    except Exception:                      # pragma: no cover - no jax oracle
-        JaxWCEngine = ()
-    if JaxWCEngine and isinstance(obj, JaxWCEngine):
+    from .sim_jax import JaxWCEngine
+    if isinstance(obj, JaxWCEngine):
         return JaxOracleEngine(jax_engine=obj, **kwargs)
     if callable(obj):
         return CallableEngine(obj, **kwargs)

@@ -60,6 +60,7 @@ import numpy as np
 
 from .devices import DeviceModel
 from .graph import DataflowGraph
+from ..kernels import default_interpret
 from ..kernels.wc_oracle.ops import wc_step
 
 F32_INF = jnp.float32(np.inf)
@@ -547,14 +548,15 @@ def makespan_fifo_batch(sg: SimGraph, assignments, backend: str = "xla",
 
     ``backend="xla"`` runs the single-episode trip ops vmapped;
     ``backend="pallas"`` routes the per-trip running-table work through
-    the fused kernels.wc_oracle step (``interpret=None`` auto-falls back
-    to the interpreter off-TPU).  Both share the trip-trimmed
-    ``_run_trips`` driver — the batch stops as soon as its longest
-    episode completes instead of always paying the static ``n_trips + 1``
-    bound — and both are decision-exact twins of the serial engine."""
+    the fused kernels.wc_oracle step (``interpret=None`` resolves
+    through :func:`repro.kernels.default_interpret`).  Both share the
+    trip-trimmed ``_run_trips`` driver — the batch stops as soon as its
+    longest episode completes instead of always paying the static
+    ``n_trips + 1`` bound — and both are decision-exact twins of the
+    serial engine."""
     if backend == "pallas":
         if interpret is None:
-            interpret = jax.default_backend() == "cpu"
+            interpret = default_interpret()
         return _makespan_fifo_batch_pallas(sg, assignments, interpret)
     if backend != "xla":
         raise ValueError(f"unknown oracle backend {backend!r}; "

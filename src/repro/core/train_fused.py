@@ -53,6 +53,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.flatten_util import ravel_pytree
 
+from ..kernels import default_interpret
 from ..train.optim import AdamState, adamw_update
 from .assign import BIG, GraphData, _device_features, _etf_update
 from .nn import apply_mlp, leaky_relu, masked_log_softmax
@@ -553,7 +554,7 @@ def build_fused_stage2(cfg: FusedStage2Config, gd: GraphData,
     n_devices`` episode shard, and gradients / advantage statistics are
     combined with a single fused ``pmean`` all-reduce over the flattened
     gradient vector.  ``spmd="shard_map"`` (default) lowers through
-    ``jax.experimental.shard_map`` with donated buffers; ``spmd="pmap"``
+    ``jax.shard_map`` with donated buffers; ``spmd="pmap"``
     keeps the legacy per-device dispatch (bit-parity-tested against
     shard_map).  The same episode keys are drawn in either mode, so the
     sampled population is identical to the single-device path; only
@@ -571,9 +572,9 @@ def build_fused_stage2(cfg: FusedStage2Config, gd: GraphData,
         raise ValueError(f"unknown spmd mode {spmd!r}")
     kb = cfg.batch_size // n_devices
     sharded = n_devices > 1
-    # resolve the Pallas interpret fallback once, at build time (a traced
-    # value cannot pick it; jit re-specializes if the backend changes)
-    oracle_interpret = jax.default_backend() == "cpu"
+    # resolve the Pallas interpret mode once, at build time (a traced
+    # value cannot pick it)
+    oracle_interpret = default_interpret()
 
     # ---- micro-chunk resolution (None = auto, 0 = force monolithic)
     if cfg.chunk_size is None:
@@ -731,8 +732,10 @@ def build_fused_stage2(cfg: FusedStage2Config, gd: GraphData,
                 "oracle_ok": ok, "best_assignments": best_a,
                 "losses": losses}
 
-    # buffer donation is a no-op (with a warning) on the CPU backend
-    donate = () if jax.default_backend() == "cpu" else (0, 1, 2)
+    # params, optimizer state and reward stats are donated: callers must
+    # hold no other reference to them (DopplerTrainer.stage2_fused swaps
+    # in the returned state)
+    donate = (0, 1, 2)
 
     if not sharded:
         return jax.jit(lambda p, o, r, k, e: chunk(p, o, r, k, e),
@@ -765,7 +768,6 @@ def build_fused_stage2(cfg: FusedStage2Config, gd: GraphData,
         return sharded_chunk
 
     # ---- shard_map: replicated state in/out, episode-sharded outputs
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec
 
     P = PartitionSpec
@@ -775,10 +777,10 @@ def build_fused_stage2(cfg: FusedStage2Config, gd: GraphData,
                  "makespans": P(None, "batch"),      # (U, K) episode-major
                  "oracle_ok": P(None, "batch"),
                  "best_assignments": P("batch")}     # (ndev*U, n)
-    inner = jax.jit(shard_map(
+    inner = jax.jit(jax.shard_map(
         lambda p, o, r, k, e: chunk(p, o, r, k, e), mesh=mesh,
         in_specs=(P(), P(), P(), P(), P()), out_specs=out_specs,
-        check_rep=False), donate_argnums=donate)
+        check_vma=False), donate_argnums=donate)
 
     def sharded_chunk(params, opt_state, rstats, key, episode):
         out = inner(params, opt_state, rstats, key, episode)

@@ -476,7 +476,9 @@ class DopplerTrainer:
         gradient-accumulation micro-chunk (None = auto).  The engine
         raises RuntimeError if the WC oracle flags any episode as
         non-converged (the flags also mask those episodes' advantages
-        in-update, so no garbage makespan reaches the gradient)."""
+        in-update, so no garbage makespan reaches the gradient); the
+        trainer then holds that dispatch's params, with its episode index
+        and reward statistics advanced to match."""
         from .sim_jax import SimGraph
         from .train_fused import (FusedStage2Config, RewardStats,
                                   build_fused_stage2)
@@ -525,17 +527,23 @@ class DopplerTrainer:
             else:
                 out = chunk(self.params, self.opt_state, rstats,
                             self.key, jnp.int32(self.episode))
-            ok = np.asarray(out["oracle_ok"])             # (u, K)
-            if not ok.all():
-                raise RuntimeError(
-                    f"WC oracle failed to converge on "
-                    f"{int((~ok).sum())}/{ok.size} episodes (deadlock); "
-                    f"their advantages were masked in-update and the "
-                    f"dispatch result was discarded")
+            # the dispatch donated the old params/opt state: adopt the
+            # new state before anything can raise
             self.params = out["params"]
             self.opt_state = out["opt_state"]
             self.key = out["key"]
             rstats = out["rstats"]
+            ok = np.asarray(out["oracle_ok"])             # (u, K)
+            if not ok.all():
+                # the params took these u updates: keep the schedules'
+                # episode index and the reward statistics in step
+                self.episode += u * batch_size
+                self._keep_reward_stats(rstats)
+                raise RuntimeError(
+                    f"WC oracle failed to converge on "
+                    f"{int((~ok).sum())}/{ok.size} episodes (deadlock); "
+                    f"their advantages were masked in-update and their "
+                    f"makespans discarded")
             ms = np.asarray(out["makespans"])             # (u, K)
             best_as = np.asarray(out["best_assignments"])  # (u, n)
             for j in range(ms.shape[0]):
@@ -553,10 +561,13 @@ class DopplerTrainer:
                 print(f"[stage2f] upd {done}/{n_updates} "
                       f"mean={ms[-1].mean()*1e3:.2f}ms "
                       f"best={self.best_time*1e3:.2f}ms")
+        self._keep_reward_stats(rstats)
+        return times
+
+    def _keep_reward_stats(self, rstats):
         self._r_sum = float(rstats.r_sum)
         self._r_sqsum = float(rstats.r_sqsum)
         self._r_count = int(rstats.r_count)
-        return times
 
     # ------------------------------------------------------- fused Stage I
     def stage1_imitation_fused(self, n_episodes: int, seed: int = 0,
@@ -887,9 +898,11 @@ class DopplerTrainer:
 def transfer(trainer: DopplerTrainer, target_graph: DataflowGraph,
              dev: DeviceModel, **kwargs) -> DopplerTrainer:
     """Few-shot transfer (Table 4 / App. J): carry the policy parameters to
-    a new graph and/or device model; the caller then runs k-shot episodes."""
+    a new graph and/or device model; the caller then runs k-shot episodes.
+    The params are copied: a fused Stage-II update donates its trainer's
+    params, which must not delete the other trainer's."""
     new = DopplerTrainer(target_graph, dev, **kwargs)
-    new.params = trainer.params
+    new.params = jax.tree_util.tree_map(jnp.copy, trainer.params)
     new.opt_state = adamw_init(new.params)
     return new
 

@@ -9,7 +9,7 @@ assignment is replicated across the repeated structure of the full model.
 
 Cost model (per primitive):
   dot_general / conv:  2 * prod(contract dims) * prod(batch/free dims)
-  reductions:          input size
+  reductions, split:   input size
   elementwise & rest:  output size
 Bytes: output nbytes (dtype-aware).
 """
@@ -23,7 +23,7 @@ from ..core.graph import DataflowGraph
 _ELEMWISE_HINT = ("add", "sub", "mul", "div", "exp", "log", "tanh", "logistic",
                   "max", "min", "pow", "rsqrt", "sqrt", "neg", "erf",
                   "integer_pow", "select_n", "convert_element_type",
-                  "custom_jvp_call", "stop_gradient")
+                  "custom_jvp_call", "stop_gradient", "square")
 
 _KIND_MAP = {
     "dot_general": "matmul",
@@ -39,6 +39,7 @@ _KIND_MAP = {
     "transpose": "squeezer",
     "concatenate": "select",
     "slice": "select",
+    "split": "select",
     "dynamic_slice": "select",
     "gather": "select",
     "scatter": "select",
@@ -77,7 +78,9 @@ def _flops_of(eqn) -> float:
         contract = float(np.prod([lhs.shape[i] for i in lc],
                                  dtype=np.float64)) if lc else 1.0
         return 2.0 * out_elems * contract
-    if prim.startswith("reduce") or prim.startswith("cum"):
+    # split: all its pieces, i.e. its input — jax 0.9 traces jnp.split as
+    # one multi-output `split` where jax 0.4.37 emitted a `slice` per piece
+    if prim.startswith("reduce") or prim.startswith("cum") or prim == "split":
         in_aval = eqn.invars[0].aval
         return float(np.prod(in_aval.shape, dtype=np.float64)) \
             if in_aval.shape else 1.0

@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import default_interpret
+
 NEG_INF = -1e30
 
 
@@ -69,10 +71,10 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_attention_bh(q, k, v, *, causal: bool = True, block_q: int = 128,
                        block_k: int = 128, interpret: bool | None = None):
     """q, k, v: (BH, S, d) with matching head counts (GQA expansion is done
-    by ops.py).  Returns (BH, S, d).  ``interpret=None`` resolves to True
-    on CPU hosts (the convention every kernels/* entry point follows)."""
+    by ops.py).  Returns (BH, S, d).  ``interpret=None`` resolves through
+    :func:`repro.kernels.default_interpret`."""
     if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+        interpret = default_interpret()
     bh, sq, d = q.shape
     sk = k.shape[1]
     block_q = min(block_q, sq)

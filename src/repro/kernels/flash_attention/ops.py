@@ -6,11 +6,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from .. import default_interpret
 from .kernel import flash_attention_bh
-
-
-def _is_cpu() -> bool:
-    return jax.default_backend() == "cpu"
 
 
 @partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
@@ -18,10 +15,10 @@ def _is_cpu() -> bool:
 def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
                     block_k: int = 128, interpret: bool | None = None):
     """q: (B, S, Hq, d); k, v: (B, S, Hkv, d) with Hq % Hkv == 0.
-    Returns (B, S, Hq, d).  On CPU hosts the kernel body runs in
-    interpret mode (same code path, Python evaluation)."""
+    Returns (B, S, Hq, d).  Off a TPU the kernel body runs in interpret
+    mode (same code path, Python evaluation)."""
     if interpret is None:
-        interpret = _is_cpu()
+        interpret = default_interpret()
     B, S, Hq, d = q.shape
     Hkv = k.shape[2]
     G = Hq // Hkv

@@ -37,7 +37,10 @@ def _agg_kernel(assign_ref, msg_ref, out_ref, acc_scr, *, n_edge_tiles):
 
     a = assign_ref[0, 0].astype(jnp.float32)       # (Nb, Eb)
     m = msg_ref[0].astype(jnp.float32)             # (Eb, d)
-    acc_scr[...] += jax.lax.dot(a, m, preferred_element_type=jnp.float32)
+    # HIGHEST: Mosaic's default f32 matmul rounds the messages to bf16 on
+    # the MXU (~1e-3 relative), which breaks parity with segment_sum
+    acc_scr[...] += jax.lax.dot(a, m, precision=jax.lax.Precision.HIGHEST,
+                                preferred_element_type=jnp.float32)
 
     @pl.when(t == n_edge_tiles - 1)
     def _done():

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import default_interpret
 from .kernel import segment_aggregate_blocked
 
 
@@ -78,14 +79,21 @@ def _segment_sum_bwd(n, node_block, edge_tile, interpret, dst, g):
 _segment_sum_vjp.defvjp(_segment_sum_fwd, _segment_sum_bwd)
 
 
-@partial(jax.jit, static_argnames=("n", "node_block", "edge_tile",
-                                   "interpret"))
 def segment_sum_mp(msg, dst, *, n: int, node_block: int = 128,
                    edge_tile: int = 128, interpret: bool | None = None):
     """msg: (m, d) edge messages; dst: (m,) destination node ids.
-    Returns (n, d) with out[v] = sum over edges with dst==v."""
+    Returns (n, d) with out[v] = sum over edges with dst==v.
+    ``interpret=None`` resolves through :func:`default_interpret`."""
     if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+        interpret = default_interpret()
+    return _segment_sum_mp(msg, dst, n=n, node_block=node_block,
+                           edge_tile=edge_tile, interpret=interpret)
+
+
+@partial(jax.jit, static_argnames=("n", "node_block", "edge_tile",
+                                   "interpret"))
+def _segment_sum_mp(msg, dst, *, n: int, node_block: int, edge_tile: int,
+                    interpret: bool):
     if msg.shape[0] == 0:
         return jnp.zeros((n, msg.shape[1]), msg.dtype)
     return _segment_sum_vjp(msg, dst, n, node_block, edge_tile, interpret)

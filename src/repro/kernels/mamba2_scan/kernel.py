@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import default_interpret
+
 
 def _gla_kernel(q_ref, k_ref, v_ref, cum_ref, y_ref, state_scr, *,
                 chunk: int, n_chunks: int):
@@ -65,10 +67,10 @@ def mamba2_chunk_scan(q, k, v, log_a, *, chunk: int = 128,
     """q, k: (BH, S, N); v: (BH, S, P); log_a: (BH, S) (log decay <= 0).
     Returns y: (BH, S, P).  Within-chunk cumulative log-decay is computed
     outside (cheap, bandwidth-bound) so the kernel is pure MXU work.
-    ``interpret=None`` resolves to True on CPU hosts (the convention
-    every kernels/* entry point follows)."""
+    ``interpret=None`` resolves through
+    :func:`repro.kernels.default_interpret`."""
     if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+        interpret = default_interpret()
     bh, s, n = q.shape
     p = v.shape[-1]
     chunk = min(chunk, s)

@@ -5,6 +5,7 @@ from functools import partial
 
 import jax
 
+from .. import default_interpret
 from .kernel import mamba2_chunk_scan
 
 
@@ -14,7 +15,7 @@ def ssd_scan(q, k, v, log_a, *, chunk: int = 128,
     """Gated-linear-attention scan.  q, k: (B, S, H, N); v: (B, S, H, P);
     log_a: (B, S, H).  Returns (B, S, H, P)."""
     if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+        interpret = default_interpret()
     B, S, H, N = q.shape
     P = v.shape[-1]
 

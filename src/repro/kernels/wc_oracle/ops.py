@@ -22,6 +22,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from .. import default_interpret
 from .kernel import wc_step_blocked
 
 
@@ -29,14 +30,19 @@ def _ceil_to(x: int, q: int) -> int:
     return ((x + q - 1) // q) * q
 
 
-@partial(jax.jit, static_argnames=("block_b", "interpret"))
 def wc_step(run, rows, ridx, *, block_b: int = 8,
             interpret: bool | None = None):
     """run: (B, R, 6) running table; rows: (B, K, 6) start rows;
     ridx: (B, K) int32 target resource per row, -1 drops.
-    Returns (run_out (B, R, 6), rho (B,) int32, e1 (B,) f32)."""
+    Returns (run_out (B, R, 6), rho (B,) int32, e1 (B,) f32).
+    ``interpret=None`` resolves through :func:`default_interpret`."""
     if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+        interpret = default_interpret()
+    return _wc_step(run, rows, ridx, block_b=block_b, interpret=interpret)
+
+
+@partial(jax.jit, static_argnames=("block_b", "interpret"))
+def _wc_step(run, rows, ridx, *, block_b: int, interpret: bool):
     B, R, _ = run.shape
     K = ridx.shape[1]
     Rp = _ceil_to(R, 128)

@@ -45,6 +45,7 @@ from ..core.simulator import WCSimulator
 from ..core.trace import utilization_ascii, write_chrome_trace
 from ..core.training import DopplerTrainer
 from ..graphs.workloads import get_workload
+from .compile_cache import use_compile_cache
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -124,8 +125,11 @@ def _save_stage(args, trainer, stage: str):
         print(f"[{stage}] checkpoint saved: {path}")
 
 
-def main(argv=None):
+def main(argv=None) -> dict:
+    """Run the pipeline; returns the final placement, CP's, and both
+    scored on the evaluation engine."""
     args = build_parser().parse_args(argv)
+    use_compile_cache()
 
     g = get_workload(args.graph)
     dev = get_device_model(args.devices)
@@ -299,16 +303,22 @@ def main(argv=None):
         _save_stage(args, trainer, "stage3")
 
     # --------------------------------------------------------------- eval
+    # each executor run is a real replay: score with Stage III's repeat
+    # count (at least 3, for a spread), not the simulator's 10 draws
+    n_eval = 10 if args.system == "sim" else max(args.repeats, 3)
     if hier_cfg is not None:
         # flat placement: best-of(policy greedy, best sample, segment-CP)
         # expanded, then bounded boundary refinement on the flat graph
         # (refined against the noise-free twin; reported on real_eval)
         a, _ = trainer.place(engine=flat_eval)
-        mean, std = eval_mean_std_engine(real_eval, a)
+        mean, std = eval_mean_std_engine(real_eval, a, n_eval)
     else:
-        mean, std, a = trainer.evaluate(real_eval)
+        mean, std, a = trainer.evaluate(real_eval, n_runs=n_eval)
+    # CP's assignment on the same engine, so the two numbers compare
+    cp_mean, _ = eval_mean_std_engine(real_eval, cp_a, n_eval)
     print(f"DOPPLER best: {mean*1e3:.2f} +- {std*1e3:.2f} ms "
-          f"({100*(1 - mean/cp_t):+.1f}% vs CP)")
+          f"({100*(1 - mean/cp_mean):+.1f}% vs CP {cp_mean*1e3:.2f} ms, "
+          f"both on {real_eval.name})")
     if args.trace or g.n <= 2000:
         res = WCSimulator(g, dev_twin, choose="fifo",
                           noise_sigma=args.noise).run(a, record=True)
@@ -316,6 +326,9 @@ def main(argv=None):
         if args.trace:
             write_chrome_trace(args.trace, res, g)
             print(f"perfetto trace: {args.trace}")
+    return {"graph": g, "devices": dev_twin, "assignment": np.asarray(a),
+            "cp_assignment": np.asarray(cp_a), "engine": real_eval.name,
+            "mean_s": mean, "cp_mean_s": cp_mean}
 
 
 def eval_mean_std_engine(engine, assignment, n_runs: int = 10):

@@ -8,27 +8,26 @@ data-parallel 'pod' axis (DESIGN.md §6).
 from __future__ import annotations
 
 import jax
-
-from ..parallel.compat import auto_axis_types, make_mesh
+from jax.sharding import AxisType
 
 
 def _auto(n):
-    return auto_axis_types(n)
+    return (AxisType.Auto,) * n
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes, axis_types=_auto(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
     """Small mesh over whatever devices exist (tests / CPU smoke)."""
     n = len(jax.devices())
     assert data * model <= n, (data, model, n)
-    return make_mesh((data, model), ("data", "model"),
-                     devices=jax.devices()[: data * model],
-                     axis_types=_auto(2))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         devices=jax.devices()[: data * model],
+                         axis_types=_auto(2))
 
 
 HW_V5E = {

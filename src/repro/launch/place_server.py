@@ -186,6 +186,8 @@ def main():
     ap.add_argument("--repeat", type=int, default=2,
                     help="re-issue the request to demonstrate the cache")
     args = ap.parse_args()
+    from .compile_cache import use_compile_cache
+    use_compile_cache()
 
     from ..graphs.workloads import get_workload
     if args.ckpt:

@@ -11,9 +11,9 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import get_abstract_mesh
 
 from ..parallel.annotate import constrain, constrain_first
-from ..parallel.compat import get_abstract_mesh
 from .common import dense_init, gated_act
 from .config import MoEConfig
 

@@ -13,9 +13,7 @@ from __future__ import annotations
 import numpy as np
 
 import jax
-from jax.sharding import PartitionSpec as P
-
-from .compat import get_abstract_mesh
+from jax.sharding import PartitionSpec as P, get_abstract_mesh
 
 BATCH = ("pod", "data")          # filtered against the ambient mesh
 MODEL = "model"

@@ -23,7 +23,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from . import compat  # noqa: F401  (installs jax.set_mesh/... fallbacks)
 from ..models.config import ModelConfig
 
 

@@ -138,3 +138,25 @@ def test_batched_rollout_and_training(diamond, dev4):
     assert len(times) == 180
     assert np.mean(times[-30:]) < np.mean(times[:30])
     assert tr.best_time <= min(times) + 1e-12
+
+
+@pytest.mark.parametrize("env_dir", [None, "from-env"])
+def test_compile_cache_placement(env_dir, tmp_path, monkeypatch):
+    """Entry points keep JAX's compile cache where JAX_COMPILATION_CACHE_DIR
+    says, untouched; otherwise at the fixed <checkout>/.jax_cache."""
+    import jax
+    from repro.launch.compile_cache import CHECKOUT, use_compile_cache
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = str(CHECKOUT / ".jax_cache")
+    else:
+        want = str(tmp_path / env_dir)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", want)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        assert use_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == (
+            before if env_dir else want)
+        assert (CHECKOUT / "chip_smoke.py").exists()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -177,6 +177,29 @@ def test_stage2_fused_raises_on_nonconverged_oracle(diamond, dev4):
     tr._fused_cache = {"sim_graph": dataclasses.replace(sg, n_trips=1)}
     with pytest.raises(RuntimeError, match="converge"):
         tr.stage2_fused(2, batch_size=4, updates_per_dispatch=2)
+    # the dispatch donated the old state: the trainer holds the new one,
+    # with its episode index and reward statistics advanced to match
+    leaves = jax.tree_util.tree_leaves((tr.params, tr.opt_state))
+    assert not any(x.is_deleted() for x in leaves)
+    assert tr.episode == 2 * 4 and tr._r_count == 2 * 4
+
+
+def test_stage2_fused_donation_keeps_transfer_source(diamond, dev4):
+    """A fused update donates its trainer's params; a trainer made by
+    transfer() from it must keep its own copy, and vice versa."""
+    from repro.core.training import transfer
+    src = make_trainer(diamond, dev4)
+    dst = transfer(src, diamond, dev4, seed=1, d_hidden=16,
+                   total_episodes=200)
+    before = [np.asarray(x) for x in jax.tree_util.tree_leaves(src.params)]
+    dst.stage2_fused(1, batch_size=4)
+    after = jax.tree_util.tree_leaves(src.params)
+    assert not any(x.is_deleted() for x in after)
+    for a, b in zip(before, after):
+        np.testing.assert_array_equal(a, np.asarray(b))
+    src.stage2_fused(1, batch_size=4)
+    assert not any(x.is_deleted()
+                   for x in jax.tree_util.tree_leaves(dst.params))
 
 
 def test_shard_map_matches_pmap_two_devices():
