@@ -19,10 +19,6 @@ llama layer):
                             the K=1024 / K=2048 scale rows ride
                             REPRO_FULL=1 or --scale.
 
-``--profile`` wraps one fused update in ``jax.profiler.trace`` and
-emits a ``train_profile_fused`` row whose derived values carry the
-trace directory (open with TensorBoard / Perfetto).
-
 Protocol: both trainers run the canonical noise-free fifo Stage-II
 configuration (the zoo_sweep setting).  Timing alternates R rounds of
 each path and reports the per-path median (robust to the shared-CPU
@@ -161,43 +157,10 @@ def bench_fused_large_batch(tag: str, graph, dev, *, batch: int = 256,
          f"eps_per_sec={batch / best:.1f} devices={n_devices}")
 
 
-def profile_fused_update(graph, dev, *, batch: int = 256,
-                         trace_dir: str | None = None):
-    """--profile: trace one compiled fused update with jax.profiler.
-
-    The first dispatch compiles outside the trace; the traced dispatch
-    is a single update, so the trace shows the steady-state fused
-    sample->score->grad->step program (and, chunked, the lax.map /
-    gradient-accumulation structure).  The trace directory lands in the
-    emitted row so CI artifacts / humans can find it."""
-    import tempfile
-
-    if trace_dir is None:
-        trace_dir = tempfile.mkdtemp(prefix="repro-train-trace-")
-    n_devices = jax.local_device_count()
-    tr = DopplerTrainer(graph, dev, seed=0, total_episodes=1_000_000)
-    tr.stage2_fused(1, batch_size=batch, updates_per_dispatch=1,
-                    n_devices=n_devices)            # compile
-    t0 = time.perf_counter()
-    with jax.profiler.trace(trace_dir):
-        tr.stage2_fused(1, batch_size=batch, updates_per_dispatch=1,
-                        n_devices=n_devices)
-    dt = time.perf_counter() - t0
-    emit("train_profile_fused", dt * 1e6,
-         f"upd_per_sec={1.0 / dt:.2f} batch={batch} "
-         f"eps_per_sec={batch / dt:.1f} trace_dir={trace_dir}")
-    print(f"# profiler trace written to {trace_dir}")
-
-
 def main(argv: list[str] | None = None) -> None:
     import argparse
 
     ap = argparse.ArgumentParser(add_help=False)
-    ap.add_argument("--profile", action="store_true",
-                    help="trace one fused update with jax.profiler")
-    ap.add_argument("--trace-dir", default=None,
-                    help="where --profile writes the trace "
-                         "(default: a fresh temp dir)")
     ap.add_argument("--scale", action="store_true",
                     default=os.environ.get("REPRO_SCALE", "0") == "1",
                     help="also run the batch-1024/2048 scale rows "
@@ -226,8 +189,6 @@ def main(argv: list[str] | None = None) -> None:
         bench_graph("1024v", synthetic_layered(64, 16), dev)
         bench_fused_large_batch("1024v", synthetic_layered(64, 16), dev,
                                 batch=1024)
-    if args.profile:
-        profile_fused_update(g512, dev, trace_dir=args.trace_dir)
 
 
 if __name__ == "__main__":

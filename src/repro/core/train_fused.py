@@ -291,9 +291,10 @@ def sample_episodes(params, gd: GraphData, keys, eps,
     distribution is unchanged, but the joint stream differs from the
     serial path's independent draw; see the module docstring).
     """
-    enc = episode_encodings(
-        params, gd.x, gd.edges, gd.edge_feat, gd.b_path, gd.t_path,
-        backend=encoder_backend)
+    with jax.named_scope("doppler.encoder"):
+        enc = episode_encodings(
+            params, gd.x, gd.edges, gd.edge_feat, gd.b_path, gd.t_path,
+            backend=encoder_backend)
     return _sample_scan(params, gd, keys, eps, sel_mode, plc_mode, enc,
                         record="full")
 
@@ -564,6 +565,14 @@ def build_fused_stage2(cfg: FusedStage2Config, gd: GraphData,
     non-converged episodes have their advantage masked to zero in-update
     and the host trainer raises — garbage makespans are never trained on
     silently.
+
+    **Trace names**: the phases run under ``jax.named_scope``s, which
+    reach the device trace as HLO ``op_name`` metadata and cost nothing
+    at run time: ``doppler.encoder`` (the sampling pass's GNN),
+    ``doppler.sample``, ``doppler.oracle``, ``doppler.grad`` (opened
+    outside ``value_and_grad``, so the loss's own encoder and the
+    backward pass keep it as a prefix) and ``doppler.adamw``.  An op
+    belongs to the outermost ``doppler.*`` scope on its path.
     """
     if cfg.batch_size % n_devices:
         raise ValueError(f"batch_size {cfg.batch_size} not divisible by "
@@ -595,10 +604,11 @@ def build_fused_stage2(cfg: FusedStage2Config, gd: GraphData,
         nsc, ngc = kb // sc, kb // gc
 
     def oracle(assignments):
-        if cfg.oracle_backend == "pallas":
-            return _makespan_fifo_batch_pallas(sg, assignments,
-                                               oracle_interpret)
-        return _makespan_fifo_batch_xla(sg, assignments)
+        with jax.named_scope("doppler.oracle"):
+            if cfg.oracle_backend == "pallas":
+                return _makespan_fifo_batch_pallas(sg, assignments,
+                                                   oracle_interpret)
+            return _makespan_fifo_batch_xla(sg, assignments)
 
     def advantages(rs, rstats):
         """Running-baseline advantages + post-update stats, with the
@@ -648,19 +658,23 @@ def build_fused_stage2(cfg: FusedStage2Config, gd: GraphData,
         params, opt_state, rstats, key, episode = carry
         key, sub = jax.random.split(key)
         eps = eps_sched(episode)
-        rec = sample_episodes(params, gd, shard_keys(sub), eps,
-                              sel_mode=cfg.sel_mode, plc_mode=cfg.plc_mode,
-                              encoder_backend=cfg.encoder_backend)
+        with jax.named_scope("doppler.sample"):
+            rec = sample_episodes(params, gd, shard_keys(sub), eps,
+                                  sel_mode=cfg.sel_mode,
+                                  plc_mode=cfg.plc_mode,
+                                  encoder_backend=cfg.encoder_backend)
         ms, ok = oracle(rec["assignment"])
         rs = jax.lax.stop_gradient(jnp.where(ok, -ms, 0.0))
         advs = jnp.where(ok, advantages(rs, rstats), 0.0)
 
-        loss, grads = jax.value_and_grad(fused_pg_loss)(
-            params, gd, rec, advs, jnp.float32(cfg.entropy_weight),
-            sel_learned=cfg.sel_learned, plc_learned=cfg.plc_learned,
-            encoder_backend=cfg.encoder_backend)
-        params, opt_state, rstats, loss = all_reduce_and_step(
-            params, opt_state, rstats, grads, loss, rs, episode)
+        with jax.named_scope("doppler.grad"):
+            loss, grads = jax.value_and_grad(fused_pg_loss)(
+                params, gd, rec, advs, jnp.float32(cfg.entropy_weight),
+                sel_learned=cfg.sel_learned, plc_learned=cfg.plc_learned,
+                encoder_backend=cfg.encoder_backend)
+        with jax.named_scope("doppler.adamw"):
+            params, opt_state, rstats, loss = all_reduce_and_step(
+                params, opt_state, rstats, grads, loss, rs, episode)
         episode = episode + cfg.batch_size
         # ship only this shard's best (valid) assignment back to the host
         best_k = jnp.argmin(jnp.where(ok, ms, jnp.inf))
@@ -672,14 +686,16 @@ def build_fused_stage2(cfg: FusedStage2Config, gd: GraphData,
         key, sub = jax.random.split(key)
         eps = eps_sched(episode)
         keys = shard_keys(sub)
-        enc = episode_encodings(
-            params, gd.x, gd.edges, gd.edge_feat, gd.b_path, gd.t_path,
-            backend=cfg.encoder_backend)
+        with jax.named_scope("doppler.encoder"):
+            enc = episode_encodings(
+                params, gd.x, gd.edges, gd.edge_feat, gd.b_path, gd.t_path,
+                backend=cfg.encoder_backend)
 
         # ---- pass 1: sample + score, O(chunk) working set per chunk
         def score_chunk(ck):
-            rec = _sample_scan(params, gd, ck, eps, cfg.sel_mode,
-                               cfg.plc_mode, enc, record="reduced")
+            with jax.named_scope("doppler.sample"):
+                rec = _sample_scan(params, gd, ck, eps, cfg.sel_mode,
+                                   cfg.plc_mode, enc, record="reduced")
             ms, ok = oracle(rec["assignment"])
             return {**rec, "ms": ms, "ok": ok}
 
@@ -704,15 +720,17 @@ def build_fused_stage2(cfg: FusedStage2Config, gd: GraphData,
                     lsum + loss_c), None
 
         gz = jax.tree_util.tree_map(jnp.zeros_like, params)
-        (gsum, lsum), _ = jax.lax.scan(
-            grad_chunk, (gz, jnp.float32(0.0)),
-            (recs, advs.reshape(ngc, gc)))
+        with jax.named_scope("doppler.grad"):
+            (gsum, lsum), _ = jax.lax.scan(
+                grad_chunk, (gz, jnp.float32(0.0)),
+                (recs, advs.reshape(ngc, gc)))
         # equal chunk sizes: mean of chunk means == batch mean
         grads = jax.tree_util.tree_map(lambda g: g / ngc, gsum)
         loss = lsum / ngc
 
-        params, opt_state, rstats, loss = all_reduce_and_step(
-            params, opt_state, rstats, grads, loss, rs, episode)
+        with jax.named_scope("doppler.adamw"):
+            params, opt_state, rstats, loss = all_reduce_and_step(
+                params, opt_state, rstats, grads, loss, rs, episode)
         episode = episode + cfg.batch_size
         assignment = recs["assignment"].reshape(kb, gd.n)
         best_k = jnp.argmin(jnp.where(ok, ms, jnp.inf))

@@ -25,6 +25,7 @@ from typing import Callable, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..train.optim import AdamState, adamw_init, adamw_update, linear_schedule
 from .assign import GraphData, build_graph_data, rollout, rollout_batch
@@ -209,6 +210,7 @@ class DopplerTrainer:
         self._r_sqsum = 0.0
         self._r_count = 0
         self.episode = 0
+        self.stage2_updates = 0      # fused Stage-II updates taken
         self.history: list[EpisodeRecord] = []
         self.best_assignment: np.ndarray | None = None
         self.best_time = np.inf
@@ -478,7 +480,14 @@ class DopplerTrainer:
         non-converged (the flags also mask those episodes' advantages
         in-update, so no garbage makespan reaches the gradient); the
         trainer then holds that dispatch's params, with its episode index
-        and reward statistics advanced to match."""
+        and reward statistics advanced to match.
+
+        Each dispatch writes three host spans on the profiler's clock:
+        ``doppler.stage2.dispatch`` (the asynchronous enqueue),
+        ``doppler.stage2.sync`` (waiting for the results) and
+        ``doppler.stage2.record`` (the per-update bookkeeping), each with
+        ``update=`` the index in ``stage2_updates`` of the dispatch's
+        first update."""
         from .sim_jax import SimGraph
         from .train_fused import (FusedStage2Config, RewardStats,
                                   build_fused_stage2)
@@ -522,18 +531,24 @@ class DopplerTrainer:
                         dataclasses.replace(cfg, updates=u), self.gd,
                         cache["sim_graph"], self.lr_sched, self.eps_sched,
                         n_devices=n_devices)
-                out = tail(self.params, self.opt_state, rstats,
-                           self.key, jnp.int32(self.episode))
+                step = tail
             else:
-                out = chunk(self.params, self.opt_state, rstats,
-                            self.key, jnp.int32(self.episode))
+                step = chunk
+            first = self.stage2_updates
+            with TraceAnnotation("doppler.stage2.dispatch", update=first):
+                out = step(self.params, self.opt_state, rstats,
+                           self.key, jnp.int32(self.episode))
             # the dispatch donated the old params/opt state: adopt the
             # new state before anything can raise
             self.params = out["params"]
             self.opt_state = out["opt_state"]
             self.key = out["key"]
+            self.stage2_updates += u
             rstats = out["rstats"]
-            ok = np.asarray(out["oracle_ok"])             # (u, K)
+            with TraceAnnotation("doppler.stage2.sync", update=first):
+                ok = np.asarray(out["oracle_ok"])             # (u, K)
+                ms = np.asarray(out["makespans"])             # (u, K)
+                best_as = np.asarray(out["best_assignments"])  # (u, n)
             if not ok.all():
                 # the params took these u updates: keep the schedules'
                 # episode index and the reward statistics in step
@@ -544,18 +559,17 @@ class DopplerTrainer:
                     f"{int((~ok).sum())}/{ok.size} episodes (deadlock); "
                     f"their advantages were masked in-update and their "
                     f"makespans discarded")
-            ms = np.asarray(out["makespans"])             # (u, K)
-            best_as = np.asarray(out["best_assignments"])  # (u, n)
-            for j in range(ms.shape[0]):
-                ts = ms[j]
-                self.episode += batch_size
-                if ts.min() < self.best_time:
-                    self.best_time = float(ts.min())
-                    self.best_assignment = best_as[j]
-                self.history.append(EpisodeRecord(
-                    self.episode, "sim_fused", float(ts.mean()),
-                    self.best_time))
-                times.extend(ts.tolist())
+            with TraceAnnotation("doppler.stage2.record", update=first):
+                for j in range(ms.shape[0]):
+                    ts = ms[j]
+                    self.episode += batch_size
+                    if ts.min() < self.best_time:
+                        self.best_time = float(ts.min())
+                        self.best_assignment = best_as[j]
+                    self.history.append(EpisodeRecord(
+                        self.episode, "sim_fused", float(ts.mean()),
+                        self.best_time))
+                    times.extend(ts.tolist())
             done += ms.shape[0]
             if log_every:
                 print(f"[stage2f] upd {done}/{n_updates} "

@@ -202,6 +202,74 @@ def test_stage2_fused_donation_keeps_transfer_source(diamond, dev4):
                    for x in jax.tree_util.tree_leaves(dst.params))
 
 
+# ------------------------------------------------------------ trace names
+PHASES = ("doppler.encoder", "doppler.sample", "doppler.oracle",
+          "doppler.grad", "doppler.adamw")
+
+
+@pytest.mark.parametrize("chunk_size", [4, 0], ids=["chunked", "monolithic"])
+def test_fused_step_names_its_phases(diamond, dev4, chunk_size):
+    """Each phase of the compiled step carries its named scope in the HLO
+    ``op_name`` metadata, and the WC oracle's kernel lies under
+    ``doppler.oracle``."""
+    import re
+
+    from repro.core.sim_jax import SimGraph
+    from repro.core.train_fused import (FusedStage2Config, RewardStats,
+                                        build_fused_stage2)
+    tr = make_trainer(diamond, dev4, oracle_backend="pallas")
+    cfg = FusedStage2Config(batch_size=8, updates=1,
+                            oracle_backend="pallas", chunk_size=chunk_size)
+    step = build_fused_stage2(cfg, tr.gd, SimGraph.build(diamond, dev4),
+                              tr.lr_sched, tr.eps_sched)
+    hlo = step.lower(tr.params, tr.opt_state, RewardStats.make(), tr.key,
+                     jnp.int32(0)).compile().as_text()
+    op_names = re.findall(r'op_name="([^"]*)"', hlo)
+
+    def outermost(op_name):
+        return next((p for p in op_name.split("/")
+                     if p.startswith("doppler.")), "")
+
+    assert {outermost(o) for o in op_names} >= set(PHASES)
+    # opened outside any transformation, every scope stays a plain path
+    # component (not only ``transpose(doppler.grad)``)
+    assert all(outermost(o) for o in op_names if "doppler." in o)
+    kernel = [o for o in op_names if "jit(_wc_step)" in o]
+    assert kernel
+    assert {outermost(o) for o in kernel} == {"doppler.oracle"}
+
+
+def test_stage2_fused_host_spans(diamond, dev4, tmp_path):
+    """One dispatch writes its three host spans once each, in the order
+    dispatch, sync, record, inside the caller's span, each with the index
+    of the dispatch's first update."""
+    tr = make_trainer(diamond, dev4)
+    tr.stage2_fused(1, batch_size=4)             # compile outside the trace
+    with jax.profiler.trace(str(tmp_path)):
+        with jax.profiler.TraceAnnotation("test.call"):
+            tr.stage2_fused(1, batch_size=4)
+    assert tr.stage2_updates == 2
+    (xspace,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    pd = jax.profiler.ProfileData.from_file(str(xspace))
+    names = ("test.call", "doppler.stage2.dispatch", "doppler.stage2.sync",
+             "doppler.stage2.record")
+    spans = {}
+    for plane in pd.planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name in names:
+                    assert ev.name not in spans, ev.name
+                    spans[ev.name] = (ev.start_ns, ev.end_ns,
+                                      {k: v for k, v in ev.stats})
+    assert set(spans) == set(names)
+    call, *phases = (spans[n] for n in names)
+    assert call[0] <= phases[0][0]
+    for (_, end, _), (start, _, _) in zip(phases, phases[1:]):
+        assert end <= start
+    assert phases[-1][1] <= call[1]
+    assert [int(p[2]["update"]) for p in phases] == [1, 1, 1]
+
+
 def test_shard_map_matches_pmap_two_devices():
     """Same-seed trajectory bit-parity: the shard_map engine (single
     fused all-reduce, donated buffers) vs the legacy pmap engine on two
