@@ -39,6 +39,9 @@ friendly per-trip work (no large dense ops, no large scatters):
   per-task ``next``).  Insertion keys are globally increasing (trip index
   × row width + emission position), replicating the serial ``(ready_time,
   insertion order)`` queue keys, so append-at-tail preserves FIFO order.
+  The queue state is stored one array per column (``Queues``): a table
+  with a 2- or 3-wide minor axis would be tiled to 128 lanes on a TPU
+  and converted between layouts on every trip.
 * One scan trip = one serial heap pop: a work-conserving start pass over
   a small carried *candidate list* (only the resource freed by the last
   completion and the ≤2C resources whose queue gained a task can start
@@ -53,6 +56,7 @@ from __future__ import annotations
 
 import dataclasses
 from functools import partial
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -196,15 +200,36 @@ def _derive_tasks(sg: SimGraph, A):
     return av, is_canon, req, edur, xdur, res_x
 
 
-def _init_episode(sg: SimGraph, av):
-    """Initial trip-loop state (tkn, hdtl, run, need, cand) for one episode."""
+class Queues(NamedTuple):
+    """Per-task and per-resource FIFO queue state of one episode, one
+    array per column: every per-trip read and write is a scalar-window
+    gather or scatter, so no table has a narrow minor axis (on a TPU a
+    2- or 3-wide minor axis is tiled to 128 lanes)."""
+    key: jnp.ndarray    # (N,) f32 insertion key (exact integer)
+    rdy: jnp.ndarray    # (N,) f32 ready time
+    nxt: jnp.ndarray    # (N,) int32 linked-list next task, -1 = end
+    hd: jnp.ndarray     # (R,) int32 head task, -1 = empty
+    tl: jnp.ndarray     # (R,) int32 tail task, -1 = empty
+
+
+class TripCarry(NamedTuple):
+    """Trip-loop state of one episode (or a batch of them, stacked)."""
+    q: Queues
+    run: jnp.ndarray    # (R, 6) running table
+    need: jnp.ndarray   # (n,) int32 unmet indegree
+    cand: jnp.ndarray   # (K,) int32 candidate resources, R = none
+    t: jnp.ndarray      # f32 simulated time
+    ms: jnp.ndarray     # f32 makespan so far
+    n_done: jnp.ndarray  # int32 completed exec tasks
+
+
+def _init_episode(sg: SimGraph, av) -> TripCarry:
+    """Initial trip-loop state for one episode."""
     n, nd, C = sg.n, sg.nd, sg.C
     mm = sg.esrc.shape[0]
     R = nd + nd * nd
     F_BIG = jnp.float32(I32_BIG)
 
-    # ---- per-task queue state: tkn[:, 0] = insertion key (exact f32
-    # int), tkn[:, 1] = ready time, tkn[:, 2] = linked-list next pointer
     ready0 = (sg.need0 == 0) & ~sg.is_input
     fseq = jnp.arange(n, dtype=jnp.float32)
 
@@ -216,21 +241,19 @@ def _init_episode(sg: SimGraph, av):
     sufmin = jax.lax.cummin(colidx[::-1], axis=0)[::-1]  # inclusive suffix
     nxt0 = jnp.concatenate([sufmin[1:], jnp.full((1, nd), I32_BIG)])
     nxt_v = jnp.take_along_axis(nxt0, av[:, None], axis=1)[:, 0]
-    tkn = jnp.stack([
-        jnp.where(ready0, fseq, F_BIG),
-        jnp.zeros(n),
-        jnp.where(ready0 & (nxt_v < I32_BIG), nxt_v.astype(jnp.float32),
-                  -1.0)], axis=1)
-    tkn = jnp.concatenate([tkn, jnp.tile(jnp.asarray([[F_BIG, 0.0, -1.0]]),
-                                         (mm, 1))])
     hd0 = jnp.where(oh & ready0[:, None], colidx, I32_BIG).min(0)
     tl0 = jnp.where(oh & ready0[:, None],
                     jnp.arange(n, dtype=jnp.int32)[:, None], -1).max(0)
-    # hdtl[:, 0] = head task, hdtl[:, 1] = tail task (-1 = empty)
-    hdtl = jnp.full((R, 2), -1)
-    hdtl = hdtl.at[:nd, 0].set(
-        jnp.where(hd0 < I32_BIG, hd0, -1).astype(jnp.int32))
-    hdtl = hdtl.at[:nd, 1].set(tl0.astype(jnp.int32))
+    q = Queues(
+        key=jnp.concatenate([jnp.where(ready0, fseq, F_BIG),
+                             jnp.full(mm, F_BIG)]),
+        rdy=jnp.zeros(n + mm),
+        nxt=jnp.concatenate([
+            jnp.where(ready0 & (nxt_v < I32_BIG), nxt_v, -1),
+            jnp.full(mm, -1, jnp.int32)]),
+        hd=jnp.full(R, -1, jnp.int32).at[:nd].set(
+            jnp.where(hd0 < I32_BIG, hd0, -1)),
+        tl=jnp.full(R, -1, jnp.int32).at[:nd].set(tl0))
 
     # run[:, :] = (end, start trip, ready time, key, task, free) per
     # resource — one row scatter per start
@@ -238,23 +261,23 @@ def _init_episode(sg: SimGraph, av):
     run = run.at[:, 0].set(F32_INF)
     run = run.at[:, 4].set(-1.0)
 
-    need = sg.need0
     K = max(nd, C + 1)
     cand = jnp.full(K, R, jnp.int32).at[:nd].set(
         jnp.arange(nd, dtype=jnp.int32))
-    return tkn, hdtl, run, need, cand
+    return TripCarry(q, run, sg.need0, cand, jnp.float32(0.0),
+                     jnp.float32(0.0), jnp.int32(0))
 
 
-def _start_pass(sg: SimGraph, dur, tkn, hdtl, run, cand, t, ftrip):
+def _start_pass(sg: SimGraph, dur, q: Queues, run, cand, t, ftrip):
     """Work-conserving start pass over the candidate resources: a free
     resource starts its queue head (duplicate candidates are idempotent —
-    same head, same row).  Returns ``(ridx, rows, hdtl)`` where
-    ``ridx == R`` drops the row and ``hdtl`` has the queue-head pops
+    same head, same row).  Returns ``(ridx, rows, q)`` where
+    ``ridx == R`` drops the row and ``q`` has the queue-head pops
     applied (advance head; clear tail when the queue empties)."""
     R = sg.nd + sg.nd * sg.nd
     cc = jnp.minimum(cand, R - 1)
     crow = run[cc]                                   # (K, 6)
-    h = jnp.where(cand < R, hdtl[cc, 0], -1)         # head task or -1
+    h = jnp.where(cand < R, q.hd[cc], -1)            # head task or -1
     # a resource whose task ends exactly at t counts as free in the
     # serial engine before its completion pops; its run slot is still
     # occupied here, so defer that start one trip (the pop at the same
@@ -264,14 +287,13 @@ def _start_pass(sg: SimGraph, dur, tkn, hdtl, run, cand, t, ftrip):
     hh = jnp.maximum(h, 0)
     end_c = t + dur[hh]
     ridx = jnp.where(go, cc, R)                      # OOB drops
-    hrow = tkn[hh]                                   # (K, 3)
     rows = jnp.stack(
-        [end_c, jnp.full_like(end_c, ftrip), hrow[:, 1], hrow[:, 0],
+        [end_c, jnp.full_like(end_c, ftrip), q.rdy[hh], q.key[hh],
          hh.astype(jnp.float32), end_c], axis=1)
-    hn = hrow[:, 2].astype(jnp.int32)
-    hdtl = hdtl.at[ridx].set(jnp.stack(
-        [hn, jnp.where(hn < 0, -1, hdtl[cc, 1])], axis=1))
-    return ridx, rows, hdtl
+    hn = q.nxt[hh]
+    return ridx, rows, q._replace(
+        hd=q.hd.at[ridx].set(hn),
+        tl=q.tl.at[ridx].set(jnp.where(hn < 0, -1, q.tl[cc])))
 
 
 def _lex_pop(run):
@@ -291,14 +313,14 @@ def _lex_pop(run):
     return rho, e1, alive
 
 
-def _readiness(sg: SimGraph, is_canon, req, res_of, tkn, hdtl, need, t,
+def _readiness(sg: SimGraph, is_canon, req, res_of, q: Queues, need, t,
                trip_idx, c, c_is_exec, alive):
     """Readiness triggered by completion ``c``, computed in the completed
     producer's out-edge row (≤C entries), in the serial emission order:
     same-device successors (succ position), then transfers (C offset,
     consumers_on first-edge order).  Same-device edges and cross edges are
     disjoint, so one C-wide row covers both.  Returns
-    ``(tkn, hdtl, need, i_res)``."""
+    ``(q, need, i_res)``."""
     n, nd, C = sg.n, sg.nd, sg.C
     mm = sg.esrc.shape[0]
     N = n + mm
@@ -335,27 +357,28 @@ def _readiness(sg: SimGraph, is_canon, req, res_of, tkn, hdtl, need, t,
     succ_task = i_task[jnp.minimum(succ_k, C - 1)]
     is_first = ~(samer & (cpos[None, :] < cpos[:, None])).any(1) & i_live
     is_last = ~has_succ & i_live
-    # one combined row scatter: (key, ready, chain-next) for the new
-    # entries plus the tail-append link from each queue's old tail
-    rtl = hdtl[jnp.minimum(i_res, R - 1), 1]
+    # the new entries' (key, ready, chain-next) plus the tail-append link
+    # from each queue's old tail: new tasks and old tails are disjoint and
+    # internally deduped, so every scatter has unique indices
+    rtl = q.tl[jnp.minimum(i_res, R - 1)]
     link_idx = jnp.where(is_first & (rtl >= 0), jnp.maximum(rtl, 0), N)
-    # new tasks and old tails are disjoint and internally deduped, so
-    # the combined row scatter has unique indices
-    tkn = tkn.at[jnp.concatenate([i_task, link_idx])].set(jnp.stack(
-        [jnp.concatenate([i_key.astype(jnp.float32), tkn[link_idx, 0]]),
-         jnp.concatenate([jnp.broadcast_to(t, (C,)), tkn[link_idx, 1]]),
-         jnp.concatenate([jnp.where(has_succ, succ_task, -1
-                                    ).astype(jnp.float32),
-                          i_task.astype(jnp.float32)])], axis=1),
+    key = q.key.at[i_task].set(i_key.astype(jnp.float32),
+                               unique_indices=True)
+    rdy = q.rdy.at[i_task].set(jnp.broadcast_to(t, (C,)),
+                               unique_indices=True)
+    nxt = q.nxt.at[jnp.concatenate([i_task, link_idx])].set(
+        jnp.concatenate([jnp.where(has_succ, succ_task, -1), i_task]),
         unique_indices=True)
-    # every live entry writes its resource's FINAL (head, tail) row, so
+    # every live entry writes its resource's FINAL (head, tail), so
     # duplicate scatter indices all carry identical values
     fst = jnp.where(samer & is_first[None, :], i_task[None, :], -1).max(1)
     lst = jnp.where(samer & is_last[None, :], i_task[None, :], -1).max(1)
-    old_hd = hdtl[jnp.minimum(i_res, R - 1), 0]
-    hdtl = hdtl.at[jnp.where(i_live, i_res, R)].set(
-        jnp.stack([jnp.where(rtl < 0, fst, old_hd), lst], axis=1))
-    return tkn, hdtl, need, i_res
+    old_hd = q.hd[jnp.minimum(i_res, R - 1)]
+    widx = jnp.where(i_live, i_res, R)
+    q = Queues(key, rdy, nxt,
+               q.hd.at[widx].set(jnp.where(rtl < 0, fst, old_hd)),
+               q.tl.at[widx].set(lst))
+    return q, need, i_res
 
 
 def _next_cand(sg: SimGraph, i_res, rho, alive):
@@ -378,53 +401,51 @@ def makespan_fifo(sg: SimGraph, assignment) -> tuple[jnp.ndarray, jnp.ndarray]:
     (the host wrapper raises, matching the numpy engines).
 
     Performance shape: each resource's FIFO queue is an intrusive linked
-    list (head/tail pointers plus a per-task ``next``), the running tasks
-    live in a compact (R, 6) per-resource table, and every per-trip update
-    is a gather or a ≤C-index scatter — the work-conserving start pass
-    only examines the carried *candidate list* (the resource freed by the
-    last completion plus the ≤C whose queue gained a task; every other
-    resource is busy or free-and-empty, an invariant the pass maintains).
-    The trip loop is a ``while_loop`` that exits when the heap drains, so
-    an episode costs exactly its own completion count.  Queue keys are
-    exact-integer float32 (SimGraph.build guarantees keys < 2**24).
+    list (head/tail pointers plus a per-task ``next``), kept with the
+    per-task keys and ready times as one array per column (``Queues``),
+    so every per-trip read and write of it is a scalar-window gather or
+    scatter.  The running tasks live in a compact (R, 6) per-resource
+    table, and every per-trip update is a gather or a ≤C-index scatter —
+    the work-conserving start pass only examines the carried *candidate
+    list* (the resource freed by the last completion plus the ≤C whose
+    queue gained a task; every other resource is busy or free-and-empty,
+    an invariant the pass maintains).  The trip loop is a fixed-trip
+    ``scan`` of ``n_trips + 1`` trips; trips after the heap drains are
+    no-ops.  Queue keys are exact-integer float32 (SimGraph.build
+    guarantees keys < 2**24).
     """
     n = sg.n
     R = sg.nd + sg.nd * sg.nd       # devices then directed channels
     av, is_canon, req, edur, xdur, res_x = _derive_tasks(sg, assignment)
     dur = jnp.concatenate([edur, xdur])
     res_of = jnp.concatenate([av, res_x])
-    tkn, hdtl, run, need, cand = _init_episode(sg, av)
 
     def trip(state):
-        (tkn, hdtl, run, need, cand, t, ms, n_done, trip_idx) = state
+        s, trip_idx = state
         ftrip = trip_idx.astype(jnp.float32)
 
-        ridx, rows, hdtl = _start_pass(sg, dur, tkn, hdtl, run, cand, t,
-                                       ftrip)
-        run = run.at[ridx].set(rows)
+        ridx, rows, q = _start_pass(sg, dur, s.q, s.run, s.cand, s.t, ftrip)
+        run = s.run.at[ridx].set(rows)
 
         rho, e1, alive = _lex_pop(run)
         c = jnp.where(alive, run[rho, 4].astype(jnp.int32), -1)
         run = run.at[jnp.where(alive, rho, R), 0].set(F32_INF)
         c_is_exec = alive & (c < n)
-        t = jnp.where(alive, e1, t)
-        ms = jnp.where(alive, e1, ms)
-        n_done = n_done + jnp.where(c_is_exec, 1, 0)
+        t = jnp.where(alive, e1, s.t)
 
-        tkn, hdtl, need, i_res = _readiness(sg, is_canon, req, res_of, tkn,
-                                            hdtl, need, t, trip_idx, c,
-                                            c_is_exec, alive)
-        cand = _next_cand(sg, i_res, rho, alive)
-        return (tkn, hdtl, run, need, cand, t, ms, n_done, trip_idx + 1)
+        q, need, i_res = _readiness(sg, is_canon, req, res_of, q, s.need, t,
+                                    trip_idx, c, c_is_exec, alive)
+        return TripCarry(q, run, need, _next_cand(sg, i_res, rho, alive), t,
+                         jnp.where(alive, e1, s.ms),
+                         s.n_done + jnp.where(c_is_exec, 1, 0)
+                         ), trip_idx + 1
 
-    state = (tkn, hdtl, run, need, cand, jnp.float32(0.0), jnp.float32(0.0),
-             jnp.int32(0), jnp.int32(0))
     # fixed-trip scan: completions are bounded by n_trips; drained trips
     # no-op (vmapped while_loop would pay a full-carry select per trip)
-    state = jax.lax.scan(lambda s, _: (trip(s), None), state, None,
-                         length=sg.n_trips + 1)[0]
-    ms, n_done = state[6], state[7]
-    return ms, n_done == sg.n_compute
+    s, _ = jax.lax.scan(lambda st, _: (trip(st), None),
+                        (_init_episode(sg, av), jnp.int32(0)), None,
+                        length=sg.n_trips + 1)[0]
+    return s.ms, s.n_done == sg.n_compute
 
 
 def _batch_setup(sg: SimGraph, assignments):
@@ -433,15 +454,12 @@ def _batch_setup(sg: SimGraph, assignments):
         lambda a: _derive_tasks(sg, a))(assignments)
     dur = jnp.concatenate([edur, xdur], axis=1)
     res_of = jnp.concatenate([av, res_x], axis=1)
-    tkn, hdtl, run, need, cand = jax.vmap(
-        lambda a: _init_episode(sg, a))(av)
-    B = assignments.shape[0]
-    carry = (tkn, hdtl, run, need, cand, jnp.zeros(B), jnp.zeros(B),
-             jnp.zeros(B, jnp.int32))
+    carry = jax.vmap(lambda a: _init_episode(sg, a))(av)
     return dur, is_canon, req, res_of, carry
 
 
-def _run_trips(sg: SimGraph, dur, is_canon, req, res_of, carry, pop_fn):
+def _run_trips(sg: SimGraph, dur, is_canon, req, res_of, carry: TripCarry,
+               pop_fn):
     """Shared batched trip loop: one iteration = one serial heap pop per
     episode, with the running-table work (start writes, lexicographic pop,
     popped-slot clear) delegated to ``pop_fn`` (vmapped XLA ops or the
@@ -465,40 +483,37 @@ def _run_trips(sg: SimGraph, dur, is_canon, req, res_of, carry, pop_fn):
     n = sg.n
 
     def cond(state):
-        carry, trip_idx = state
-        n_done = carry[7]
+        s, trip_idx = state
         return ((trip_idx < sg.n_trips + 1)
-                & jnp.any(n_done < sg.n_compute))
+                & jnp.any(s.n_done < sg.n_compute))
 
     def body(state):
-        carry, trip_idx = state
-        tkn, hdtl, run, need, cand, t, ms, n_done = carry
+        s, trip_idx = state
         ftrip = trip_idx.astype(jnp.float32)
 
-        ridx, rows, hdtl = jax.vmap(
-            lambda du, tk, hd, rn, cd, tt: _start_pass(
-                sg, du, tk, hd, rn, cd, tt, ftrip)
-        )(dur, tkn, hdtl, run, cand, t)
-        run, rho, e1 = pop_fn(run, rows, ridx)
+        ridx, rows, q = jax.vmap(
+            lambda du, qq, rn, cd, tt: _start_pass(
+                sg, du, qq, rn, cd, tt, ftrip)
+        )(dur, s.q, s.run, s.cand, s.t)
+        run, rho, e1 = pop_fn(s.run, rows, ridx)
         alive = jnp.isfinite(e1)
         c = jnp.where(alive, jnp.take_along_axis(
             run[:, :, 4], rho[:, None], axis=1)[:, 0].astype(jnp.int32), -1)
         c_is_exec = alive & (c < n)
-        t = jnp.where(alive, e1, t)
-        ms = jnp.where(alive, e1, ms)
-        n_done = n_done + jnp.where(c_is_exec, 1, 0)
+        t = jnp.where(alive, e1, s.t)
 
-        tkn, hdtl, need, i_res = jax.vmap(
-            lambda ic, rq, ro, tk, hd, ne, tt, cv, ce, al: _readiness(
-                sg, ic, rq, ro, tk, hd, ne, tt, trip_idx, cv, ce, al)
-        )(is_canon, req, res_of, tkn, hdtl, need, t, c, c_is_exec, alive)
+        q, need, i_res = jax.vmap(
+            lambda ic, rq, ro, qq, ne, tt, cv, ce, al: _readiness(
+                sg, ic, rq, ro, qq, ne, tt, trip_idx, cv, ce, al)
+        )(is_canon, req, res_of, q, s.need, t, c, c_is_exec, alive)
         cand = jax.vmap(
             lambda ir, rh, al: _next_cand(sg, ir, rh, al))(i_res, rho, alive)
-        return ((tkn, hdtl, run, need, cand, t, ms, n_done), trip_idx + 1)
+        return TripCarry(q, run, need, cand, t, jnp.where(alive, e1, s.ms),
+                         s.n_done + jnp.where(c_is_exec, 1, 0)
+                         ), trip_idx + 1
 
-    carry, _ = jax.lax.while_loop(cond, body, (carry, jnp.int32(0)))
-    ms, n_done = carry[6], carry[7]
-    return ms, n_done == sg.n_compute
+    s, _ = jax.lax.while_loop(cond, body, (carry, jnp.int32(0)))
+    return s.ms, s.n_done == sg.n_compute
 
 
 @jax.jit

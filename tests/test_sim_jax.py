@@ -116,3 +116,50 @@ def test_simgraph_key_capacity_guard():
     # checking the documented bound on a real small graph
     sg = SimGraph.build(make_chain(6), uniform_box(2))
     assert 2 * sg.koff < 2 ** 24
+
+
+# --------------------------------------------------------------- layout
+def _trip_loop_carries(jaxpr):
+    """Avals carried by the outermost ``while`` / ``scan`` loops."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "while":
+            found.append([v.aval for v in eqn.outvars])
+        elif eqn.primitive.name == "scan":
+            found.append([v.aval for v in
+                          eqn.outvars[:eqn.params["num_carry"]]])
+        else:
+            for p in eqn.params.values():
+                for sub in p if isinstance(p, (tuple, list)) else (p,):
+                    if isinstance(sub, ClosedJaxpr):
+                        found += _trip_loop_carries(sub.jaxpr)
+                    elif isinstance(sub, Jaxpr):
+                        found += _trip_loop_carries(sub)
+    return found
+
+
+@pytest.mark.parametrize("path", ["single", "xla", "pallas"])
+def test_trip_loop_carries_no_narrow_table(path):
+    """The trip loop keeps its queue tables one array per column: no
+    carried array has a 2- or 3-wide minor axis, which a TPU would tile to
+    128 lanes (the (R, 6) running table is the kernel's own layout)."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.sim_jax import (_makespan_fifo_batch_pallas,
+                                    _makespan_fifo_batch_xla, makespan_fifo)
+    g, dev = make_diamond(8), uniform_box(4)
+    sg = SimGraph.build(g, dev)
+    A = jnp.zeros((5, g.n), jnp.int32)
+    fn, arg = {
+        "single": (lambda a: makespan_fifo(sg, a), A[0]),
+        "xla": (lambda a: _makespan_fifo_batch_xla(sg, a), A),
+        "pallas": (lambda a: _makespan_fifo_batch_pallas(sg, a, True), A),
+    }[path]
+    loops = _trip_loop_carries(jax.make_jaxpr(fn)(arg).jaxpr)
+    assert len(loops) == 1
+    shapes = [a.shape for a in loops[0]]
+    run = (sg.nd + sg.nd ** 2, 6)
+    assert (run if path == "single" else (5, *run)) in shapes
+    assert not [s for s in shapes if s and s[-1] in (2, 3)], shapes

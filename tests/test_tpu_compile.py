@@ -10,7 +10,9 @@ The topology is described inside the fixture, never at import: only one
 process may load the TPU library, and the test runner's workers all
 import this file.
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -97,3 +99,41 @@ def test_fused_stage2_step_compiles(one_chip, monkeypatch):
          jnp.int32(0)))
     hlo = step.lower(*args).compile().as_text()
     assert "tpu_custom_call" in hlo
+
+
+def _tiled_arrays(hlo: str):
+    """Each array type in compiled TPU text, as ``(text, dims, tiled
+    size / logical size)``: the layout's first tile pads the most-minor
+    physical dims up to its sizes."""
+    for m in re.finditer(r"\b[a-z]+\d*\[([\d,]*)\]\{([^}]*)\}", hlo):
+        dims = [int(d) for d in m.group(1).split(",") if d]
+        order, _, tiling = m.group(2).partition(":")
+        phys = [dims[int(i)] for i in reversed(order.split(",")) if i]
+        tile = re.match(r"T\(([\d,]+)\)", tiling)
+        tile = [int(t) for t in tile.group(1).split(",")] if tile else []
+        phys = [1] * (len(tile) - len(phys)) + phys
+        for k, t in enumerate(tile, start=len(phys) - len(tile)):
+            phys[k] = -(-phys[k] // t) * t
+        yield m.group(0), dims, math.prod(phys) / max(1, math.prod(dims))
+
+
+def test_oracle_queue_table_dense_on_chip(one_chip):
+    """model:olmo_1b, batch 128, Pallas oracle: the trip loop's per-task
+    queue table (N tasks, or B*N flattened) is stored one array per
+    column, so no array over it is tiled to more than twice its bytes (a
+    3-wide column axis in the lanes pads to 128, about 43x)."""
+    from repro.core.devices import get_device_model
+    from repro.core.sim_jax import SimGraph, _makespan_fifo_batch_pallas
+    from repro.graphs.workloads import get_workload
+
+    g, dev = get_workload("model:olmo_1b"), get_device_model("tpu_v5e_2x2")
+    sg = SimGraph.build(g, dev)
+    B, N = 128, sg.n + sg.esrc.shape[0]
+    hlo = _compiled_text(
+        lambda a: _makespan_fifo_batch_pallas(sg, a, interpret=False),
+        _spec((B, g.n), jnp.int32, one_chip))
+    assert "tpu_custom_call" in hlo
+    over_n = [(t, r) for t, dims, r in _tiled_arrays(hlo)
+              if N in dims or B * N in dims]
+    assert over_n
+    assert not [(t, r) for t, r in over_n if r > 2], over_n
