@@ -41,7 +41,12 @@ friendly per-trip work (no large dense ops, no large scatters):
   insertion order)`` queue keys, so append-at-tail preserves FIFO order.
   The queue state is stored one array per column (``Queues``): a table
   with a 2- or 3-wide minor axis would be tiled to 128 lanes on a TPU
-  and converted between layouts on every trip.
+  and converted between layouts on every trip.  A batch stores each
+  column, and the indegrees, flat and episode-major: the per-episode
+  helpers only *plan* a trip's writes (gathers and unique slots), and
+  each column takes the whole batch's writes as one 1-D scatter — a
+  vmapped scatter into a ``(B, X)`` array costs a TPU about twelve times
+  as much per update.
 * One scan trip = one serial heap pop: a work-conserving start pass over
   a small carried *candidate list* (only the resource freed by the last
   completion and the ≤2C resources whose queue gained a task can start
@@ -201,22 +206,26 @@ def _derive_tasks(sg: SimGraph, A):
 
 
 class Queues(NamedTuple):
-    """Per-task and per-resource FIFO queue state of one episode, one
-    array per column: every per-trip read and write is a scalar-window
-    gather or scatter, so no table has a narrow minor axis (on a TPU a
-    2- or 3-wide minor axis is tiled to 128 lanes)."""
-    key: jnp.ndarray    # (N,) f32 insertion key (exact integer)
-    rdy: jnp.ndarray    # (N,) f32 ready time
-    nxt: jnp.ndarray    # (N,) int32 linked-list next task, -1 = end
-    hd: jnp.ndarray     # (R,) int32 head task, -1 = empty
-    tl: jnp.ndarray     # (R,) int32 tail task, -1 = empty
+    """Per-task and per-resource FIFO queue state, one array per column:
+    every per-trip read and write is a scalar-window gather or scatter,
+    so no table has a narrow minor axis (on a TPU a 2- or 3-wide minor
+    axis is tiled to 128 lanes).  A batch of B episodes stores each
+    column flat and episode-major (episode b's slot i at ``b*X + i``), so
+    a trip writes all B episodes with one 1-D scatter per column."""
+    key: jnp.ndarray    # (B*N,) f32 insertion key (exact integer)
+    rdy: jnp.ndarray    # (B*N,) f32 ready time
+    nxt: jnp.ndarray    # (B*N,) int32 linked-list next task, -1 = end
+    hd: jnp.ndarray     # (B*R,) int32 head task, -1 = empty
+    tl: jnp.ndarray     # (B*R,) int32 tail task, -1 = empty
 
 
 class TripCarry(NamedTuple):
-    """Trip-loop state of one episode (or a batch of them, stacked)."""
+    """Trip-loop state of B episodes (B = 1 for the single scan): the
+    queues and indegrees flat and episode-major, the rest stacked on a
+    leading batch axis (none when unbatched)."""
     q: Queues
     run: jnp.ndarray    # (R, 6) running table
-    need: jnp.ndarray   # (n,) int32 unmet indegree
+    need: jnp.ndarray   # (B*n,) int32 unmet indegree
     cand: jnp.ndarray   # (K,) int32 candidate resources, R = none
     t: jnp.ndarray      # f32 simulated time
     ms: jnp.ndarray     # f32 makespan so far
@@ -268,16 +277,43 @@ def _init_episode(sg: SimGraph, av) -> TripCarry:
                      jnp.float32(0.0), jnp.int32(0))
 
 
-def _start_pass(sg: SimGraph, dur, q: Queues, run, cand, t, ftrip):
-    """Work-conserving start pass over the candidate resources: a free
-    resource starts its queue head (duplicate candidates are idempotent —
-    same head, same row).  Returns ``(ridx, rows, q)`` where
-    ``ridx == R`` drops the row and ``q`` has the queue-head pops
-    applied (advance head; clear tail when the queue empties)."""
+def _flat_set(arr, idx, val):
+    """Write every episode's planned ``(slot, value)`` pairs into the flat
+    episode-major ``arr`` as one 1-D scatter.  ``idx`` is ``(B, k)`` (or
+    ``(k,)`` for one episode) per-episode slots; a slot ``>= X``, the
+    per-episode size, drops.  A dropped write points past the whole
+    array: episode b's own ``b*X + X`` would alias episode b+1's slot 0.
+    Each episode's kept slots are unique."""
+    idx = idx.reshape(-1, idx.shape[-1])
+    B = idx.shape[0]
+    X = arr.shape[0] // B
+    off = X * jnp.arange(B, dtype=jnp.int32)[:, None]
+    flat = jnp.where(idx < X, idx + off, B * X)
+    return arr.at[flat.ravel()].set(val.ravel(), unique_indices=True,
+                                    mode="drop")
+
+
+def _write_queues(q: Queues, qw: Queues) -> Queues:
+    """Apply a plan's ``(slot, value)`` writes, one column at a time
+    (``None`` = the column is not written)."""
+    return Queues(*(a if w is None else _flat_set(a, *w)
+                    for a, w in zip(q, qw)))
+
+
+def _start_pass(sg: SimGraph, dur, q: Queues, run, cand, t, ftrip, b):
+    """Plan of the work-conserving start pass of episode ``b`` over its
+    candidate resources: a free resource starts its queue head (duplicate
+    candidates are idempotent — same head, same row).  Reads the flat
+    queues ``q`` at the episode's slots and writes nothing.  Returns
+    ``(ridx, rows, qw)`` where ``ridx == R`` drops the row and ``qw``
+    holds the queue-head pops (advance head; clear tail when the queue
+    empties) as per-episode ``(slot, value)`` writes for
+    :func:`_write_queues`, from each resource's first candidate only."""
     R = sg.nd + sg.nd * sg.nd
+    N = sg.n + sg.esrc.shape[0]
     cc = jnp.minimum(cand, R - 1)
     crow = run[cc]                                   # (K, 6)
-    h = jnp.where(cand < R, q.hd[cc], -1)            # head task or -1
+    h = jnp.where(cand < R, q.hd[b * R + cc], -1)    # head task or -1
     # a resource whose task ends exactly at t counts as free in the
     # serial engine before its completion pops; its run slot is still
     # occupied here, so defer that start one trip (the pop at the same
@@ -285,15 +321,21 @@ def _start_pass(sg: SimGraph, dur, q: Queues, run, cand, t, ftrip):
     # therefore the schedule, are unchanged)
     go = (h >= 0) & (crow[:, 5] <= t) & ~jnp.isfinite(crow[:, 0])
     hh = jnp.maximum(h, 0)
+    th = b * N + hh
     end_c = t + dur[hh]
     ridx = jnp.where(go, cc, R)                      # OOB drops
     rows = jnp.stack(
-        [end_c, jnp.full_like(end_c, ftrip), q.rdy[hh], q.key[hh],
+        [end_c, jnp.full_like(end_c, ftrip), q.rdy[th], q.key[th],
          hh.astype(jnp.float32), end_c], axis=1)
-    hn = q.nxt[hh]
-    return ridx, rows, q._replace(
-        hd=q.hd.at[ridx].set(hn),
-        tl=q.tl.at[ridx].set(jnp.where(hn < 0, -1, q.tl[cc])))
+    hn = q.nxt[th]
+    # a repeated candidate's first occurrence writes the resource's
+    # (head, tail), so each write's slots are unique
+    kpos = jnp.arange(cand.shape[0])
+    dup = ((cand[:, None] == cand[None, :])
+           & (kpos[None, :] < kpos[:, None])).any(1)
+    w = jnp.where(go & ~dup, cc, R)
+    tl = jnp.where(hn < 0, -1, q.tl[b * R + cc])
+    return ridx, rows, Queues(None, None, None, (w, hn), (w, tl))
 
 
 def _lex_pop(run):
@@ -314,13 +356,16 @@ def _lex_pop(run):
 
 
 def _readiness(sg: SimGraph, is_canon, req, res_of, q: Queues, need, t,
-               trip_idx, c, c_is_exec, alive):
-    """Readiness triggered by completion ``c``, computed in the completed
-    producer's out-edge row (≤C entries), in the serial emission order:
-    same-device successors (succ position), then transfers (C offset,
-    consumers_on first-edge order).  Same-device edges and cross edges are
-    disjoint, so one C-wide row covers both.  Returns
-    ``(q, need, i_res)``."""
+               trip_idx, c, c_is_exec, alive, b):
+    """Plan of the readiness triggered by episode ``b``'s completion
+    ``c``, computed in the completed producer's out-edge row (≤C
+    entries), in the serial emission order: same-device successors (succ
+    position), then transfers (C offset, consumers_on first-edge order).
+    Same-device edges and cross edges are disjoint, so one C-wide row
+    covers both.  Reads the flat ``q`` and ``need`` at the episode's
+    slots and writes nothing.  Returns ``(qw, need_w, i_res)``: the queue
+    and indegree writes as per-episode ``(slot, value)`` pairs with
+    unique slots."""
     n, nd, C = sg.n, sg.nd, sg.C
     mm = sg.esrc.shape[0]
     N = n + mm
@@ -333,14 +378,15 @@ def _readiness(sg: SimGraph, is_canon, req, res_of, q: Queues, need, t,
     pvalid = (prow >= 0) & alive
     ptrig = pvalid & (req[pe] == c)
     pdst = sg.edst[pe]
-    need = need.at[jnp.where(ptrig, pdst, n)].add(
-        -ptrig.astype(jnp.int32))
-    # last decrement wins the emission slot: max triggered succ
-    # position per destination vertex (tiny C x C pass); parallel
-    # edges collapse onto that single slot
-    samew = pdst[:, None] == pdst[None, :]
-    maxpos = jnp.where(samew & ptrig[None, :], cpos[None, :], -1).max(1)
-    nw = ptrig & (need[pdst] == 0) & (cpos == maxpos)
+    # each triggered edge decrements its destination's indegree once
+    # (parallel edges each count); the last decrement wins the emission
+    # slot: max triggered succ position per destination vertex (tiny
+    # C x C pass), which writes the decremented indegree
+    trig_w = (pdst[:, None] == pdst[None, :]) & ptrig[None, :]
+    maxpos = jnp.where(trig_w, cpos[None, :], -1).max(1)
+    last = ptrig & (cpos == maxpos)
+    left = need[b * n + pdst] - trig_w.sum(1, dtype=jnp.int32)
+    nw = last & (left == 0)
     nx = pvalid & c_is_exec & is_canon[pe]
     i_live = nw | nx
     base = n + trip_idx * sg.seqw
@@ -359,26 +405,21 @@ def _readiness(sg: SimGraph, is_canon, req, res_of, q: Queues, need, t,
     is_last = ~has_succ & i_live
     # the new entries' (key, ready, chain-next) plus the tail-append link
     # from each queue's old tail: new tasks and old tails are disjoint and
-    # internally deduped, so every scatter has unique indices
-    rtl = q.tl[jnp.minimum(i_res, R - 1)]
+    # internally deduped; each resource's first entry writes its FINAL
+    # (head, tail)
+    rq = b * R + jnp.minimum(i_res, R - 1)
+    rtl = q.tl[rq]
     link_idx = jnp.where(is_first & (rtl >= 0), jnp.maximum(rtl, 0), N)
-    key = q.key.at[i_task].set(i_key.astype(jnp.float32),
-                               unique_indices=True)
-    rdy = q.rdy.at[i_task].set(jnp.broadcast_to(t, (C,)),
-                               unique_indices=True)
-    nxt = q.nxt.at[jnp.concatenate([i_task, link_idx])].set(
-        jnp.concatenate([jnp.where(has_succ, succ_task, -1), i_task]),
-        unique_indices=True)
-    # every live entry writes its resource's FINAL (head, tail), so
-    # duplicate scatter indices all carry identical values
-    fst = jnp.where(samer & is_first[None, :], i_task[None, :], -1).max(1)
     lst = jnp.where(samer & is_last[None, :], i_task[None, :], -1).max(1)
-    old_hd = q.hd[jnp.minimum(i_res, R - 1)]
-    widx = jnp.where(i_live, i_res, R)
-    q = Queues(key, rdy, nxt,
-               q.hd.at[widx].set(jnp.where(rtl < 0, fst, old_hd)),
-               q.tl.at[widx].set(lst))
-    return q, need, i_res
+    w = jnp.where(is_first, i_res, R)
+    qw = Queues(
+        key=(i_task, i_key.astype(jnp.float32)),
+        rdy=(i_task, jnp.broadcast_to(t, (C,))),
+        nxt=(jnp.concatenate([i_task, link_idx]),
+             jnp.concatenate([jnp.where(has_succ, succ_task, -1), i_task])),
+        hd=(w, jnp.where(rtl < 0, i_task, q.hd[rq])),
+        tl=(w, lst))
+    return qw, (jnp.where(last, pdst, n), left), i_res
 
 
 def _next_cand(sg: SimGraph, i_res, rho, alive):
@@ -404,8 +445,10 @@ def makespan_fifo(sg: SimGraph, assignment) -> tuple[jnp.ndarray, jnp.ndarray]:
     list (head/tail pointers plus a per-task ``next``), kept with the
     per-task keys and ready times as one array per column (``Queues``),
     so every per-trip read and write of it is a scalar-window gather or
-    scatter.  The running tasks live in a compact (R, 6) per-resource
-    table, and every per-trip update is a gather or a ≤C-index scatter —
+    scatter — through the same plan (``_start_pass``, ``_readiness``) and
+    flat write (``_flat_set``) helpers as the batch loop, with B = 1.
+    The running tasks live in a compact (R, 6) per-resource table, and
+    every per-trip update is a gather or a ≤C-index scatter —
     the work-conserving start pass only examines the carried *candidate
     list* (the resource freed by the last completion plus the ≤C whose
     queue gained a task; every other resource is busy or free-and-empty,
@@ -424,7 +467,9 @@ def makespan_fifo(sg: SimGraph, assignment) -> tuple[jnp.ndarray, jnp.ndarray]:
         s, trip_idx = state
         ftrip = trip_idx.astype(jnp.float32)
 
-        ridx, rows, q = _start_pass(sg, dur, s.q, s.run, s.cand, s.t, ftrip)
+        ridx, rows, qw = _start_pass(sg, dur, s.q, s.run, s.cand, s.t,
+                                     ftrip, 0)
+        q = _write_queues(s.q, qw)
         run = s.run.at[ridx].set(rows)
 
         rho, e1, alive = _lex_pop(run)
@@ -433,8 +478,9 @@ def makespan_fifo(sg: SimGraph, assignment) -> tuple[jnp.ndarray, jnp.ndarray]:
         c_is_exec = alive & (c < n)
         t = jnp.where(alive, e1, s.t)
 
-        q, need, i_res = _readiness(sg, is_canon, req, res_of, q, s.need, t,
-                                    trip_idx, c, c_is_exec, alive)
+        qw, need_w, i_res = _readiness(sg, is_canon, req, res_of, q, s.need,
+                                       t, trip_idx, c, c_is_exec, alive, 0)
+        q, need = _write_queues(q, qw), _flat_set(s.need, *need_w)
         return TripCarry(q, run, need, _next_cand(sg, i_res, rho, alive), t,
                          jnp.where(alive, e1, s.ms),
                          s.n_done + jnp.where(c_is_exec, 1, 0)
@@ -449,12 +495,15 @@ def makespan_fifo(sg: SimGraph, assignment) -> tuple[jnp.ndarray, jnp.ndarray]:
 
 
 def _batch_setup(sg: SimGraph, assignments):
-    """Vmapped per-episode task systems + initial trip-loop carry."""
+    """Vmapped per-episode task systems + initial trip-loop carry, its
+    queues and indegrees flattened episode-major."""
     av, is_canon, req, edur, xdur, res_x = jax.vmap(
         lambda a: _derive_tasks(sg, a))(assignments)
     dur = jnp.concatenate([edur, xdur], axis=1)
     res_of = jnp.concatenate([av, res_x], axis=1)
     carry = jax.vmap(lambda a: _init_episode(sg, a))(av)
+    carry = carry._replace(q=Queues(*(x.reshape(-1) for x in carry.q)),
+                           need=carry.need.reshape(-1))
     return dur, is_canon, req, res_of, carry
 
 
@@ -481,6 +530,7 @@ def _run_trips(sg: SimGraph, dur, is_canon, req, res_of, carry: TripCarry,
     garbage and callers must raise or mask).
     """
     n = sg.n
+    ep = jnp.arange(dur.shape[0], dtype=jnp.int32)
 
     def cond(state):
         s, trip_idx = state
@@ -491,10 +541,13 @@ def _run_trips(sg: SimGraph, dur, is_canon, req, res_of, carry: TripCarry,
         s, trip_idx = state
         ftrip = trip_idx.astype(jnp.float32)
 
-        ridx, rows, q = jax.vmap(
-            lambda du, qq, rn, cd, tt: _start_pass(
-                sg, du, qq, rn, cd, tt, ftrip)
-        )(dur, s.q, s.run, s.cand, s.t)
+        # the plans are vmapped and only gather from the flat queues,
+        # closed over (unbatched); the writes are applied outside the vmap
+        ridx, rows, qw = jax.vmap(
+            lambda b, du, rn, cd, tt: _start_pass(
+                sg, du, s.q, rn, cd, tt, ftrip, b)
+        )(ep, dur, s.run, s.cand, s.t)
+        q = _write_queues(s.q, qw)
         run, rho, e1 = pop_fn(s.run, rows, ridx)
         alive = jnp.isfinite(e1)
         c = jnp.where(alive, jnp.take_along_axis(
@@ -502,10 +555,11 @@ def _run_trips(sg: SimGraph, dur, is_canon, req, res_of, carry: TripCarry,
         c_is_exec = alive & (c < n)
         t = jnp.where(alive, e1, s.t)
 
-        q, need, i_res = jax.vmap(
-            lambda ic, rq, ro, qq, ne, tt, cv, ce, al: _readiness(
-                sg, ic, rq, ro, qq, ne, tt, trip_idx, cv, ce, al)
-        )(is_canon, req, res_of, q, s.need, t, c, c_is_exec, alive)
+        qw, need_w, i_res = jax.vmap(
+            lambda b, ic, rq, ro, tt, cv, ce, al: _readiness(
+                sg, ic, rq, ro, q, s.need, tt, trip_idx, cv, ce, al, b)
+        )(ep, is_canon, req, res_of, t, c, c_is_exec, alive)
+        q, need = _write_queues(q, qw), _flat_set(s.need, *need_w)
         cand = jax.vmap(
             lambda ir, rh, al: _next_cand(sg, ir, rh, al))(i_res, rho, alive)
         return TripCarry(q, run, need, cand, t, jnp.where(alive, e1, s.ms),
@@ -518,9 +572,8 @@ def _run_trips(sg: SimGraph, dur, is_canon, req, res_of, carry: TripCarry,
 
 @jax.jit
 def _makespan_fifo_batch_xla(sg: SimGraph, assignments):
-    """Batched :func:`makespan_fifo`: same per-trip ops as the
-    single-episode scan, vmapped, driven by the trip-trimmed
-    ``_run_trips`` loop."""
+    """Batched :func:`makespan_fifo`: the running-table work as vmapped
+    XLA ops, driven by the trip-trimmed ``_run_trips`` loop."""
     R = sg.nd + sg.nd * sg.nd
     dur, is_canon, req, res_of, carry = _batch_setup(sg, assignments)
 
@@ -561,7 +614,7 @@ def makespan_fifo_batch(sg: SimGraph, assignments, backend: str = "xla",
                         interpret: bool | None = None):
     """(K, n) assignments -> ((K,) makespans, (K,) ok flags), one dispatch.
 
-    ``backend="xla"`` runs the single-episode trip ops vmapped;
+    ``backend="xla"`` runs the running-table work as vmapped XLA ops;
     ``backend="pallas"`` routes the per-trip running-table work through
     the fused kernels.wc_oracle step (``interpret=None`` resolves
     through :func:`repro.kernels.default_interpret`).  Both share the

@@ -193,6 +193,60 @@ def test_trip_trimmed_batch_decision_exact():
         np.testing.assert_array_equal(np.asarray(ms), singles)
 
 
+def _parallel_edge_graph():
+    """Vertex 0 is a compute root that fans out to five consumers; two
+    producers each reach one consumer over two parallel edges (``freeze``
+    drops parallel edges, so they are put back after it, last in both the
+    edge list and the producer's successor list)."""
+    from repro.core.graph import DataflowGraph
+    g = DataflowGraph("parallel")
+    for k in range(11):
+        g.add_vertex("input" if k == 1 else "matmul",
+                     flops=(1 + k % 3) * 1e9, out_bytes=(1 + k % 4) * 1e6)
+    for s, d in [(0, 2), (1, 2), (0, 3), (0, 4), (2, 4), (0, 5), (0, 6),
+                 (3, 7), (5, 7), (4, 8), (6, 8), (7, 8), (1, 9), (8, 10),
+                 (9, 10)]:
+        g.add_edge(s, d)
+    g.freeze()
+    for s, d in [(0, 4), (3, 7)]:
+        g.edges.append((s, d))
+        g.succs[s].append(d)
+        g.preds[d].append(s)
+    return g
+
+
+def test_flat_trip_writes_decision_exact_on_parallel_edges():
+    """The batched trip loop writes every episode's queues and indegrees
+    into flat episode-major arrays, deduplicated: one write per resource
+    from repeated candidates, one indegree write per destination however
+    many triggered edges reach it.  A graph whose parallel edges make an
+    indegree fall by 2 in one trip, and whose root's five consumers on
+    one device repeat that device among a trip's candidates, keeps both
+    batch backends bit-equal to the per-episode single scan and inside
+    the serial engine's f32 band.  Vertex 0 is a compute task, so a
+    dropped write that aliased the next episode's slot 0 would show."""
+    import jax.numpy as jnp
+
+    from repro.core.sim_jax import (SimGraph, makespan_fifo,
+                                    makespan_fifo_batch)
+
+    g, dev = _parallel_edge_graph(), uniform_box(3)
+    sg = SimGraph.build(g, dev)
+    assert int(np.asarray(sg.need0)[4]) == 3 and g.m == 17
+    rng = np.random.default_rng(23)
+    A = np.concatenate([np.zeros((1, g.n), np.int64),
+                        rng.integers(0, dev.n, (5, g.n))])
+    singles = np.asarray([float(makespan_fifo(sg, jnp.asarray(a))[0])
+                          for a in A], np.float32)
+    sim = WCSimulator(g, dev, choose="fifo", noise_sigma=0.0)
+    serial = np.array([sim.run(a).makespan for a in A])
+    np.testing.assert_allclose(singles, serial, rtol=1e-4)
+    for backend in ("xla", "pallas"):
+        ms, ok = makespan_fifo_batch(sg, jnp.asarray(A), backend=backend)
+        assert np.asarray(ok).all()
+        np.testing.assert_array_equal(np.asarray(ms), singles)
+
+
 def test_oracle_ok_flag_flags_starved_trip_loop():
     """Both batched backends and the single-episode scan must report
     ok=False (not a garbage makespan) when the trip budget is too small

@@ -119,24 +119,61 @@ def test_simgraph_key_capacity_guard():
 
 
 # --------------------------------------------------------------- layout
-def _trip_loop_carries(jaxpr):
-    """Avals carried by the outermost ``while`` / ``scan`` loops."""
+def _subjaxprs(eqn):
     from jax.extend.core import ClosedJaxpr, Jaxpr
+    for p in eqn.params.values():
+        for sub in p if isinstance(p, (tuple, list)) else (p,):
+            if isinstance(sub, ClosedJaxpr):
+                yield sub.jaxpr
+            elif isinstance(sub, Jaxpr):
+                yield sub
+
+
+def _trip_loops(jaxpr):
+    """The outermost ``while`` / ``scan`` equations."""
     found = []
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "while":
-            found.append([v.aval for v in eqn.outvars])
-        elif eqn.primitive.name == "scan":
-            found.append([v.aval for v in
-                          eqn.outvars[:eqn.params["num_carry"]]])
+        if eqn.primitive.name in ("while", "scan"):
+            found.append(eqn)
         else:
-            for p in eqn.params.values():
-                for sub in p if isinstance(p, (tuple, list)) else (p,):
-                    if isinstance(sub, ClosedJaxpr):
-                        found += _trip_loop_carries(sub.jaxpr)
-                    elif isinstance(sub, Jaxpr):
-                        found += _trip_loop_carries(sub)
+            for sub in _subjaxprs(eqn):
+                found += _trip_loops(sub)
     return found
+
+
+def _trip_loop_carries(jaxpr):
+    """Avals carried by the outermost ``while`` / ``scan`` loops."""
+    return [[v.aval for v in (eqn.outvars if eqn.primitive.name == "while"
+                              else eqn.outvars[:eqn.params["num_carry"]])]
+            for eqn in _trip_loops(jaxpr)]
+
+
+def _scatter_operands(jaxpr):
+    """Operand shapes of every scatter in ``jaxpr``, outside the Pallas
+    kernel's wrapper (which pads the running table to its own layout)."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name.startswith("scatter"):
+            found.append(eqn.invars[0].aval.shape)
+        if eqn.params.get("name") != "_wc_step":
+            for sub in _subjaxprs(eqn):
+                found += _scatter_operands(sub)
+    return found
+
+
+def _oracle_jaxpr(path, sg, n, B=5):
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.sim_jax import (_makespan_fifo_batch_pallas,
+                                    _makespan_fifo_batch_xla, makespan_fifo)
+    A = jnp.zeros((B, n), jnp.int32)
+    fn, arg = {
+        "single": (lambda a: makespan_fifo(sg, a), A[0]),
+        "xla": (lambda a: _makespan_fifo_batch_xla(sg, a), A),
+        "pallas": (lambda a: _makespan_fifo_batch_pallas(sg, a, True), A),
+    }[path]
+    return jax.make_jaxpr(fn)(arg).jaxpr
 
 
 @pytest.mark.parametrize("path", ["single", "xla", "pallas"])
@@ -144,22 +181,31 @@ def test_trip_loop_carries_no_narrow_table(path):
     """The trip loop keeps its queue tables one array per column: no
     carried array has a 2- or 3-wide minor axis, which a TPU would tile to
     128 lanes (the (R, 6) running table is the kernel's own layout)."""
-    import jax
-    import jax.numpy as jnp
-
-    from repro.core.sim_jax import (_makespan_fifo_batch_pallas,
-                                    _makespan_fifo_batch_xla, makespan_fifo)
     g, dev = make_diamond(8), uniform_box(4)
     sg = SimGraph.build(g, dev)
-    A = jnp.zeros((5, g.n), jnp.int32)
-    fn, arg = {
-        "single": (lambda a: makespan_fifo(sg, a), A[0]),
-        "xla": (lambda a: _makespan_fifo_batch_xla(sg, a), A),
-        "pallas": (lambda a: _makespan_fifo_batch_pallas(sg, a, True), A),
-    }[path]
-    loops = _trip_loop_carries(jax.make_jaxpr(fn)(arg).jaxpr)
+    loops = _trip_loop_carries(_oracle_jaxpr(path, sg, g.n))
     assert len(loops) == 1
     shapes = [a.shape for a in loops[0]]
     run = (sg.nd + sg.nd ** 2, 6)
     assert (run if path == "single" else (5, *run)) in shapes
     assert not [s for s in shapes if s and s[-1] in (2, 3)], shapes
+
+
+@pytest.mark.parametrize("path", ["single", "xla", "pallas"])
+def test_trip_loop_scatters_are_flat(path):
+    """Every queue and indegree write in the trip loop is one 1-D scatter
+    over a flat, episode-major array for the whole batch: the start
+    pass's head and tail, and the readiness's key, ready time, next
+    pointer, head, tail and indegree.  A vmapped scatter into a (B, X)
+    array costs about twelve times as much per update on a TPU.  The
+    only other scatters are the running table's (..., R, 6) writes."""
+    g, dev = make_diamond(8), uniform_box(4)
+    sg = SimGraph.build(g, dev)
+    (loop,) = _trip_loops(_oracle_jaxpr(path, sg, g.n))
+    shapes = [s for sub in _subjaxprs(loop) for s in _scatter_operands(sub)]
+    R, N = sg.nd + sg.nd ** 2, sg.n + sg.esrc.shape[0]
+    B = 1 if path == "single" else 5
+    flat = [s for s in shapes if len(s) == 1]
+    assert sorted(flat) == sorted([(B * R,)] * 4 + [(B * N,)] * 3
+                                  + [(B * sg.n,)]), shapes
+    assert all(s[-2:] == (R, 6) for s in shapes if len(s) > 1), shapes

@@ -137,3 +137,26 @@ def test_oracle_queue_table_dense_on_chip(one_chip):
               if N in dims or B * N in dims]
     assert over_n
     assert not [(t, r) for t, r in over_n if r > 2], over_n
+
+
+def test_oracle_scatters_flat_on_chip(one_chip):
+    """model:olmo_1b, batch 128, Pallas oracle: the trip loop writes its
+    queues and indegrees as 1-D scatters over the flat episode-by-slot
+    index, so no compiled scatter writes a rank-2 (128, X) array (a
+    vmapped 2-D scatter costs about twelve times as much per update)."""
+    from repro.core.devices import get_device_model
+    from repro.core.sim_jax import SimGraph, _makespan_fifo_batch_pallas
+    from repro.graphs.workloads import get_workload
+
+    g, dev = get_workload("model:olmo_1b"), get_device_model("tpu_v5e_2x2")
+    sg = SimGraph.build(g, dev)
+    B = 128
+    hlo = _compiled_text(
+        lambda a: _makespan_fifo_batch_pallas(sg, a, interpret=False),
+        _spec((B, g.n), jnp.int32, one_chip))
+    scattered = [[int(d) for d in dims.split(",") if d]
+                 for line in hlo.splitlines() if " scatter(" in line
+                 for dims in re.findall(r"\[([\d,]*)\]",
+                                        line.split(" scatter(")[0])]
+    assert [B * g.n] in scattered
+    assert not [d for d in scattered if len(d) == 2 and d[0] == B], scattered
